@@ -180,7 +180,8 @@ print("RESULT " + json.dumps(dict(
 
 def _run_child(shape: dict, workers: int) -> dict:
     """One worker count in a fresh backend; returns its RESULT payload."""
-    from benchmarks.common import subprocess_env
+    from benchmarks.common import require_cpu_parent, subprocess_env
+    require_cpu_parent("bench_frontend")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = subprocess_env(repo, host_devices=workers)
     env["PYTHONPATH"] = repo + os.pathsep + env["PYTHONPATH"]
@@ -189,6 +190,9 @@ def _run_child(shape: dict, workers: int) -> dict:
         offered=OFFERED_LOAD, verify=VERIFY_SAMPLE))
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=1800)
+    if out.returncode != 0:
+        raise RuntimeError(f"frontend child (workers={workers}) exited "
+                           f"{out.returncode}: {out.stderr[-500:]}")
     for line in out.stdout.splitlines():
         if line.startswith("RESULT "):
             return json.loads(line[len("RESULT "):])

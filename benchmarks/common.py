@@ -12,18 +12,40 @@ import numpy as np
 from repro.utils.platform import host_device_env
 
 
+class DeviceChildError(RuntimeError):
+    """A harness would fork JAX children while this process is not on the
+    CPU: a chip belongs to one process, so a child could not reach it."""
+
+
+def require_cpu_parent(harness: str) -> None:
+    """Refuse to fork device children unless this process runs on CPU.
+
+    The forced-host-device children exist to fake a multi-device CPU; on
+    an accelerator the parent already holds the chip (and the real
+    devices need no faking), so forking would fail or hang the child.
+    """
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise DeviceChildError(
+            f"{harness} forks one JAX child per device count, which only "
+            f"works on the CPU backend; this process runs on {backend!r} "
+            "and holds the device")
+
+
 def subprocess_env(repo_root: str, host_devices: int | None = None) -> dict:
     """Environment for a benchmark subprocess (fresh JAX backend).
 
     Device count is fixed at backend init, so multi-device benches fork
     children instead of reconfiguring in-process. This routes the
     ``XLA_FLAGS`` merge through ``repro.utils.platform.host_device_env``
-    (one implementation, not per-bench string building) and pins
-    ``PYTHONPATH`` to the repo's ``src``.
+    (one implementation, not per-bench string building), pins
+    ``PYTHONPATH`` to the repo's ``src``, and pins the child to the CPU
+    backend so it never contends for an accelerator.
     """
     env = (dict(os.environ) if host_devices is None
            else host_device_env(host_devices))
     env["PYTHONPATH"] = os.path.join(repo_root, "src")
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

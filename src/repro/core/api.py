@@ -54,6 +54,7 @@ from typing import Any, ClassVar
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import assign as assign_mod
@@ -594,7 +595,6 @@ def _build_fit_sharded(mesh, cfg: GeekConfig, kind: str, axis: str,
     """
     from repro.core.distributed import (_gather_rows, collect_seed_rows,
                                         discover_sharded)
-    from repro.utils.compat import shard_map
 
     if discovery == "sharded":
         def body(key, *present):

@@ -81,9 +81,23 @@ def mode_centers(codes: jax.Array, seeds: Seeds, *, attr_chunk: int = 64
 # One-pass assignment (jnp path; Pallas kernel in repro.kernels)
 # ---------------------------------------------------------------------------
 
+_MIN_DOT_ROWS = 8
+
+
 def assign_l2(x: jax.Array, centers: jax.Array, center_valid: jax.Array,
               *, block: int = 4096) -> tuple[jax.Array, jax.Array]:
-    """Nearest centroid under Euclidean distance. Returns (labels, sq_dists)."""
+    """Nearest centroid under Euclidean distance. Returns (labels, sq_dists).
+
+    A row's result does not depend on the batch it arrives in: a one-row
+    ``x @ centers.T`` lowers to a matrix-vector product, which sums in
+    another order than the matrix-matrix product of larger batches, so
+    batches under ``_MIN_DOT_ROWS`` rows are zero-padded up to it.
+    """
+    n = x.shape[0]
+    if n < _MIN_DOT_ROWS:
+        xp = jnp.pad(x, ((0, _MIN_DOT_ROWS - n), (0, 0)))
+        labels, d2 = assign_l2(xp, centers, center_valid, block=block)
+        return labels[:n], d2[:n]
     csq = jnp.sum(centers * centers, axis=-1)
     inf = jnp.array(jnp.finfo(x.dtype).max, x.dtype)
 

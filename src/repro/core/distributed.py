@@ -72,6 +72,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -81,8 +83,7 @@ from repro.core.buckets import BucketTables
 from repro.core.geek import GeekConfig, _reinsert_none
 from repro.core.model import (GeekModel, patch_probed_fallback, predict,
                               predict_probed)
-from repro.core.silk import select_top_groups, silk_round
-from repro.utils.compat import axis_size, shard_map
+from repro.core.silk import select_top_groups, silk_round, silk_rounds
 from repro.utils.hashing import derive_hash_keys
 
 
@@ -646,14 +647,9 @@ def fit_dense_sharded(x_local: jax.Array, key: jax.Array, cfg: GeekConfig,
     valid = jnp.ones_like(flat_ids, dtype=bool)
     table_keys = derive_hash_keys(k_silk, (cfg.silk_l + 1, cfg.silk_k))
 
-    rounds = jax.vmap(
-        lambda tk: silk_round(flat_ids, flat_seg, valid, mt * t, tk,
-                              cfg.delta, 2, cfg.pair_cap)
-    )(table_keys[:cfg.silk_l])
-    offs = (jnp.arange(cfg.silk_l, dtype=jnp.int32) * cfg.pair_cap)[:, None]
-    lgroup = jnp.where(rounds.valid, rounds.group + offs, -1).reshape(-1)
-    lids = rounds.id.reshape(-1)
-    lvalid = rounds.valid.reshape(-1)
+    lgroup, lids, lvalid, round_overflow = silk_rounds(
+        flat_ids, flat_seg, valid, mt * t, table_keys[:cfg.silk_l],
+        cfg.delta, cfg.pair_cap)
 
     # C_shared sync (small!) — the paper's communication-cost trick
     gg = jax.lax.all_gather(lgroup, axis)                    # (g, L*cap)
@@ -667,7 +663,7 @@ def fit_dense_sharded(x_local: jax.Array, key: jax.Array, cfg: GeekConfig,
     dedup = silk_round(gi.reshape(-1), seg, gv.reshape(-1), group_cap,
                        table_keys[cfg.silk_l], 1, 1, cfg.pair_cap)
     seeds = select_top_groups(dedup, cfg.pair_cap, cfg.k_max)
-    overflow = rounds.overflow.sum() + dedup.overflow
+    overflow = round_overflow + dedup.overflow
 
     # -- phase 3: local centroids + psum, one-pass local assignment --------
     lo = idx * nl

@@ -79,8 +79,7 @@ def minhash_over_segments(
     """(num_segments,) uint32 signature per bucket = K segment-min hashes mixed.
 
     This is MinHash applied to *buckets as sets of data ids* — the core of
-    SILK (paper §3.2). The Pallas `minhash_buckets` kernel accelerates the
-    same computation; this jnp version is the oracle and CPU path.
+    SILK (paper §3.2); one path on every backend.
     """
     K = keys.shape[0]
     sig = jnp.zeros((num_segments,), jnp.uint32)

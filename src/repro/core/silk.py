@@ -11,8 +11,8 @@ SILK round over the cores themselves (min_bin_size=1, delta=1) performs the
 paper's near-duplicate removal.
 
 Everything is expressed as fixed-shape sort + segment ops (TPU-native
-equivalent of the paper's GPU hash tables — see DESIGN.md §2). The rounds
-are vmapped over the L SILK tables.
+equivalent of the paper's GPU hash tables — see DESIGN.md §2). The L SILK
+rounds run one after another (``silk_rounds``).
 """
 from __future__ import annotations
 
@@ -195,6 +195,30 @@ def select_top_groups(pairs: SeedPairs, group_cap: int, k_max: int) -> Seeds:
     return Seeds(jnp.where(valid, new_group, -1), pairs.id, valid, k_star, k_max)
 
 
+def silk_rounds(flat_ids, flat_seg, entry_valid, nbcap: int,
+                table_keys: jax.Array, delta: int, pair_cap: int):
+    """The L seeding rounds of Algorithm 4, one per row of ``table_keys``.
+
+    The rounds run one after another (``lax.map``), not vmapped: each
+    sorts all m·n bucket entries, and the L rounds' sort temporaries
+    held at once do not fit one chip's HBM at n = 10^6. Every fit path
+    seeds through here, so they all share that bound.
+
+    Returns ``(group, id, valid, overflow)``: the rounds' pairs
+    concatenated, round r's group ids offset by ``r * pair_cap`` so they
+    stay distinct, and the rounds' total overflow.
+    """
+    rounds = jax.lax.map(
+        lambda tk: silk_round(flat_ids, flat_seg, entry_valid, nbcap, tk,
+                              delta, 2, pair_cap),
+        table_keys)
+    offs = (jnp.arange(table_keys.shape[0], dtype=jnp.int32)
+            * pair_cap)[:, None]
+    group = jnp.where(rounds.valid, rounds.group + offs, -1).reshape(-1)
+    return (group, rounds.id.reshape(-1), rounds.valid.reshape(-1),
+            rounds.overflow.sum())
+
+
 def silk_seeding(
     buckets: BucketTables,
     key: jax.Array,
@@ -215,17 +239,9 @@ def silk_seeding(
     nbcap = buckets.total_bucket_cap
 
     table_keys = derive_hash_keys(key, (silk_l + 1, silk_k))
-
-    rounds = jax.vmap(
-        lambda tk: silk_round(flat_ids, flat_seg, entry_valid, nbcap, tk,
-                              delta, 2, pair_cap)
-    )(table_keys[:silk_l])
-
-    # stack rounds; group ids offset per round (each round's groups < pair_cap)
-    offs = (jnp.arange(silk_l, dtype=jnp.int32) * pair_cap)[:, None]
-    cat_group = jnp.where(rounds.valid, rounds.group + offs, -1).reshape(-1)
-    cat_ids = rounds.id.reshape(-1)
-    cat_valid = rounds.valid.reshape(-1)
+    cat_group, cat_ids, cat_valid, round_overflow = silk_rounds(
+        flat_ids, flat_seg, entry_valid, nbcap, table_keys[:silk_l], delta,
+        pair_cap)
     group_cap = silk_l * pair_cap
 
     # dedup round: cores are buckets now; singleton bins are kept (a unique
@@ -235,5 +251,5 @@ def silk_seeding(
                        table_keys[silk_l], 1, 1, pair_cap)
 
     seeds = select_top_groups(dedup, pair_cap, k_max)
-    overflow = rounds.overflow.sum() + dedup.overflow
+    overflow = round_overflow + dedup.overflow
     return seeds, overflow

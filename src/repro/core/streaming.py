@@ -57,6 +57,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -199,7 +200,6 @@ def _assign_chunk_fn(donate: bool, mesh=None, axis: str = "data",
     if mesh is None:
         return jax.jit(chunk_body, static_argnames=("k_max",),
                        donate_argnums=(1,) if donate else ())
-    from repro.utils.compat import shard_map
 
     def step(model, parts, k_max):
         """Sharded chunk step: shard rows, assign, pmax the radius."""

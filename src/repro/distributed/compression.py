@@ -27,8 +27,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from repro.utils.compat import axis_size
+from jax.lax import axis_size
 
 
 def quantize_int8(x: jax.Array):

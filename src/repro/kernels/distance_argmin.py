@@ -12,7 +12,7 @@ embarrassingly parallel; the k axis carries the scratch, so the grid is
 annotated ``dimension_semantics=("parallel", "arbitrary")``.
 
 Tile sizes default to the shape-keyed autotuner (`repro.kernels.autotune`)
-instead of hard-coded blocks; explicit bn/bk/chunk overrides remain for
+instead of hard-coded blocks; explicit bn/bk overrides remain for
 tests and benchmarking.
 
 Three metrics:
@@ -20,8 +20,8 @@ Three metrics:
                      accumulates per-cluster partial sums + counts in the
                      same pass (``accumulate=True``) so a Lloyd refinement
                      sweep needs no second pass over the data.
-  - Hamming        : #mismatching attributes (VPU equality counts,
-                     chunked over d) ≈ (1 − Jaccard)·d on minwise codes.
+  - Hamming        : #mismatching attributes (VPU equality counts, one
+                     attribute per step) ≈ (1 − Jaccard)·d on minwise codes.
   - Hamming packed : same counts on bit-packed uint32 codes — XOR +
                      field-collapse + SWAR popcount over d·b/32 words,
                      32/b× less HBM traffic and no (bn, bk, d) equality
@@ -39,18 +39,17 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import autotune
 from repro.kernels.pack import field_mismatch_count
 
-_PARAMS = pltpu.TPUCompilerParams(
+_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"))
 # the accumulating variant writes one shared (k, d) output block from every
 # n-tile, so neither grid axis is safe to parallelize
-_PARAMS_ACC = pltpu.TPUCompilerParams(
+_PARAMS_ACC = pltpu.CompilerParams(
     dimension_semantics=("arbitrary", "arbitrary"))
 
 
-def _resolve_tiles(kind: str, n: int, k: int, d: int, itemsize: int,
-                   bn, bk, chunk):
+def _resolve_tiles(kind: str, n: int, k: int, d: int, itemsize: int, bn, bk):
     tc = autotune.select_tiles(kind, n, k, d, itemsize)
-    return (bn or tc.bn, bk or tc.bk, chunk or tc.chunk)
+    return bn or tc.bn, bk or tc.bk
 
 
 # ---------------------------------------------------------------------------
@@ -143,7 +142,8 @@ def distance_argmin_l2(x: jax.Array, centers: jax.Array, center_valid: jax.Array
     additionally (per-cluster partial sums (k, d) f32, counts (k,) f32).
     Shapes are padded to tile multiples here; d is zero-padded (zeros do
     not change L2). ``accumulate`` pins the (k_pad, d_pad) accumulator in
-    VMEM for the whole grid — it needs k·d ≲ 2M f32 on current TPUs; use
+    VMEM for the whole grid — it needs k·d ≲ 2M f32 within TPU v5e's 16 MiB
+    scoped-VMEM default (``autotune.V5E_SCOPED_VMEM_BYTES``); use
     the jnp second pass (`assign_l2_with_partials`) beyond that."""
     n, d = x.shape
     k = centers.shape[0]
@@ -157,7 +157,7 @@ def distance_argmin_l2(x: jax.Array, centers: jax.Array, center_valid: jax.Array
         tc = autotune.select_tiles("l2", n, k, d, 4, budget)
         bn, bk = bn or tc.bn, bk or tc.bk
     else:
-        bn, bk, _ = _resolve_tiles("l2", n, k, d, 4, bn, bk, None)
+        bn, bk = _resolve_tiles("l2", n, k, d, 4, bn, bk)
     npad, kpad = (-n) % bn, (-k) % bk
     dpad = (-d) % 128  # MXU lane alignment
     xp = jnp.pad(x.astype(jnp.float32), ((0, npad), (0, dpad)))
@@ -217,11 +217,30 @@ def distance_argmin_l2(x: jax.Array, centers: jax.Array, center_valid: jax.Array
 
 
 # ---------------------------------------------------------------------------
-# Hamming kernel (unpacked categorical codes)
+# Hamming kernels (unpacked codes, and bit-packed codes — DESIGN.md §6)
+#
+# Both walk the attributes (words) one at a time, statically unrolled: the
+# point column x[:, a] (bn, 1) meets the center row cT[a] (1, bk) of the
+# transposed center tile, so every step is a lane-dense (bn, bk) VPU op.
+# A (bn, bk, chunk) broadcast would put the narrow chunk on the 128-wide
+# lane axis, and Mosaic only lowers a lane offset it can prove 128-aligned,
+# so a dynamic chunk loop does not compile for the chip.
 # ---------------------------------------------------------------------------
 
-def _ham_kernel(x_ref, c_ref, valid_ref, lab_ref, dist_ref, minv, argv,
-                *, bk: int, nk: int, d: int, chunk: int):
+def _int_min_argmin(dist):
+    """Row min and first index of the min of an int32 (bn, bk) block,
+    both (bn, 1). Mosaic lowers argmin for float32 only, so the index is
+    a min over the column iota where the row min is hit — the same
+    lowest-index tie-break as ``jnp.argmin``."""
+    m = jnp.min(dist, axis=-1, keepdims=True)
+    col = jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
+    arg = jnp.min(jnp.where(dist == m, col, jnp.int32(dist.shape[1])),
+                  axis=-1, keepdims=True)
+    return m, arg
+
+
+def _ham_kernel(x_ref, ct_ref, valid_ref, lab_ref, dist_ref, minv, argv,
+                *, bk: int, nk: int, d: int, packed_bits: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -229,26 +248,22 @@ def _ham_kernel(x_ref, c_ref, valid_ref, lab_ref, dist_ref, minv, argv,
         minv[...] = jnp.full_like(minv, jnp.int32(jnp.iinfo(jnp.int32).max))
         argv[...] = jnp.zeros_like(argv)
 
-    x = x_ref[...]                                           # (bn, d) int32
-    c = c_ref[...]                                           # (bk, d) int32
-    nchunks = d // chunk
+    x, ct = x_ref[...], ct_ref[...]                          # (bn, d), (d, bk)
+    dist = jnp.zeros((x.shape[0], bk), jnp.int32)
+    for a in range(d):
+        xa = x[:, a:a + 1]                                   # (bn, 1)
+        ca = ct[a:a + 1, :]                                  # (1, bk)
+        if packed_bits:
+            dist = dist + field_mismatch_count(xa ^ ca, packed_bits)
+        else:
+            dist = dist + (xa != ca).astype(jnp.int32)
+    dist = jnp.where(valid_ref[...] != 0, dist,
+                     jnp.int32(jnp.iinfo(jnp.int32).max))
 
-    def body(ci, acc):
-        xs = jax.lax.dynamic_slice_in_dim(x, ci * chunk, chunk, 1)
-        cs = jax.lax.dynamic_slice_in_dim(c, ci * chunk, chunk, 1)
-        eq = (xs[:, None, :] == cs[None, :, :]).astype(jnp.int32)
-        return acc + jnp.sum(eq, axis=-1)
-
-    matches = jax.lax.fori_loop(0, nchunks, body,
-                                jnp.zeros((x.shape[0], c.shape[0]), jnp.int32))
-    dist = d - matches
-    dist = jnp.where(valid_ref[...] != 0, dist, jnp.int32(jnp.iinfo(jnp.int32).max))
-
-    local_arg = jnp.argmin(dist, axis=-1).astype(jnp.int32)
-    local_min = jnp.min(dist, axis=-1)
-    better = local_min[:, None] < minv[...]
-    argv[...] = jnp.where(better, local_arg[:, None] + j * bk, argv[...])
-    minv[...] = jnp.where(better, local_min[:, None], minv[...])
+    local_min, local_arg = _int_min_argmin(dist)
+    better = local_min < minv[...]
+    argv[...] = jnp.where(better, local_arg + j * bk, argv[...])
+    minv[...] = jnp.where(better, local_min, minv[...])
 
     @pl.when(j == nk - 1)
     def _flush():
@@ -256,31 +271,29 @@ def _ham_kernel(x_ref, c_ref, valid_ref, lab_ref, dist_ref, minv, argv,
         dist_ref[...] = minv[...]
 
 
-@functools.partial(jax.jit, static_argnames=("bn", "bk", "chunk", "interpret"))
-def distance_argmin_hamming(codes: jax.Array, centers: jax.Array,
-                            center_valid: jax.Array, *, bn: int | None = None,
-                            bk: int | None = None, chunk: int | None = None,
-                            interpret: bool = False) -> tuple[jax.Array, jax.Array]:
-    """Returns (labels (n,), mismatch count (n,) int32). Padding uses
-    distinct sentinels so padded attributes never match."""
-    n, d = codes.shape
+def _hamming_call(x, centers, center_valid, *, kind: str, packed_bits: int,
+                  bn, bk, interpret: bool):
+    """Pad, transpose the centers and run ``_ham_kernel``. Padded center
+    columns are masked invalid; padded point rows are sliced off."""
+    n, d = x.shape
     k = centers.shape[0]
-    bn, bk, chunk = _resolve_tiles("hamming", n, k, d, 4, bn, bk, chunk)
-    npad, kpad, dpad = (-n) % bn, (-k) % bk, (-d) % chunk
-    xp = jnp.pad(codes.astype(jnp.int32), ((0, npad), (0, dpad)),
-                 constant_values=-1)
-    cp = jnp.pad(centers.astype(jnp.int32), ((0, kpad), (0, dpad)),
-                 constant_values=-2)
+    bn, bk = _resolve_tiles(kind, n, k, d, 4, bn, bk)
+    npad, kpad = (-n) % bn, (-k) % bk
+    xp = jnp.pad(x, ((0, npad), (0, 0)))
+    ct = jnp.pad(centers, ((0, kpad), (0, 0))).T             # (d, k+pad)
     vp = jnp.pad(center_valid.astype(jnp.int32), (0, kpad))
-    np_, kp_, dp_ = n + npad, k + kpad, d + dpad
+    np_, kp_ = n + npad, k + kpad
     nk = kp_ // bk
+    cost = (autotune.cost_hamming_packed(np_, kp_, d) if packed_bits
+            else autotune.cost_hamming(np_, kp_, d))
 
     lab, dist = pl.pallas_call(
-        functools.partial(_ham_kernel, bk=bk, nk=nk, d=dp_, chunk=chunk),
+        functools.partial(_ham_kernel, bk=bk, nk=nk, d=d,
+                          packed_bits=packed_bits),
         grid=(np_ // bn, nk),
         in_specs=[
-            pl.BlockSpec((bn, dp_), lambda i, j: (i, 0)),
-            pl.BlockSpec((bk, dp_), lambda i, j: (j, 0)),
+            pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((d, bk), lambda i, j: (0, j)),
             pl.BlockSpec((1, bk), lambda i, j: (0, j)),
         ],
         out_specs=[
@@ -296,100 +309,37 @@ def distance_argmin_hamming(codes: jax.Array, centers: jax.Array,
             pltpu.VMEM((bn, 1), jnp.int32),
         ],
         compiler_params=_PARAMS,
-        cost_estimate=autotune.cost_hamming(np_, kp_, dp_),
+        cost_estimate=cost,
         interpret=interpret,
-    )(xp, cp, vp[None, :])
-    # padded attributes never match either sentinel -> subtract them back out
-    return lab[:n, 0], dist[:n, 0] - dpad
+    )(xp, ct, vp[None, :])
+    return lab[:n, 0], dist[:n, 0]
 
 
-# ---------------------------------------------------------------------------
-# Packed Hamming kernel (bit-packed codes, XOR + popcount — DESIGN.md §6)
-# ---------------------------------------------------------------------------
-
-def _ham_packed_kernel(x_ref, c_ref, valid_ref, lab_ref, dist_ref, minv, argv,
-                       *, bk: int, nk: int, w: int, chunk: int, bits: int):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        minv[...] = jnp.full_like(minv, jnp.int32(jnp.iinfo(jnp.int32).max))
-        argv[...] = jnp.zeros_like(argv)
-
-    x = x_ref[...]                                           # (bn, w) uint32
-    c = c_ref[...]                                           # (bk, w) uint32
-    nchunks = w // chunk
-
-    def body(ci, acc):
-        xs = jax.lax.dynamic_slice_in_dim(x, ci * chunk, chunk, 1)
-        cs = jax.lax.dynamic_slice_in_dim(c, ci * chunk, chunk, 1)
-        z = xs[:, None, :] ^ cs[None, :, :]                  # (bn, bk, chunk)
-        return acc + jnp.sum(field_mismatch_count(z, bits), axis=-1)
-
-    dist = jax.lax.fori_loop(0, nchunks, body,
-                             jnp.zeros((x.shape[0], c.shape[0]), jnp.int32))
-    dist = jnp.where(valid_ref[...] != 0, dist, jnp.int32(jnp.iinfo(jnp.int32).max))
-
-    local_arg = jnp.argmin(dist, axis=-1).astype(jnp.int32)
-    local_min = jnp.min(dist, axis=-1)
-    better = local_min[:, None] < minv[...]
-    argv[...] = jnp.where(better, local_arg[:, None] + j * bk, argv[...])
-    minv[...] = jnp.where(better, local_min[:, None], minv[...])
-
-    @pl.when(j == nk - 1)
-    def _flush():
-        lab_ref[...] = argv[...]
-        dist_ref[...] = minv[...]
+@functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
+def distance_argmin_hamming(codes: jax.Array, centers: jax.Array,
+                            center_valid: jax.Array, *, bn: int | None = None,
+                            bk: int | None = None,
+                            interpret: bool = False) -> tuple[jax.Array, jax.Array]:
+    """Returns (labels (n,), mismatch count (n,) int32)."""
+    return _hamming_call(codes.astype(jnp.int32), centers.astype(jnp.int32),
+                         center_valid, kind="hamming", packed_bits=0,
+                         bn=bn, bk=bk, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "bn", "bk", "chunk",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("bits", "bn", "bk", "interpret"))
 def distance_argmin_hamming_packed(packed: jax.Array, packed_centers: jax.Array,
                                    center_valid: jax.Array, *, bits: int,
                                    bn: int | None = None, bk: int | None = None,
-                                   chunk: int | None = None,
                                    interpret: bool = False
                                    ) -> tuple[jax.Array, jax.Array]:
     """Fused argmin over bit-packed codes (see `repro.kernels.pack`).
 
     packed: (n, w) uint32, packed_centers: (k, w) uint32, both from
     `pack_codes(..., bits)`. Returns (labels (n,), mismatch count (n,)).
-    Word padding is zero on both sides, so padded fields never mismatch —
-    counts are exact with no sentinel correction.
+    Unused fields of the last word are zero on both sides, so they never
+    mismatch — counts are exact with no sentinel correction.
     """
-    n, w = packed.shape
-    k = packed_centers.shape[0]
-    bn, bk, chunk = _resolve_tiles("hamming_packed", n, k, w, 4, bn, bk, chunk)
-    npad, kpad, wpad = (-n) % bn, (-k) % bk, (-w) % chunk
-    xp = jnp.pad(packed.astype(jnp.uint32), ((0, npad), (0, wpad)))
-    cp = jnp.pad(packed_centers.astype(jnp.uint32), ((0, kpad), (0, wpad)))
-    vp = jnp.pad(center_valid.astype(jnp.int32), (0, kpad))
-    np_, kp_, wp_ = n + npad, k + kpad, w + wpad
-    nk = kp_ // bk
-
-    lab, dist = pl.pallas_call(
-        functools.partial(_ham_packed_kernel, bk=bk, nk=nk, w=wp_,
-                          chunk=chunk, bits=bits),
-        grid=(np_ // bn, nk),
-        in_specs=[
-            pl.BlockSpec((bn, wp_), lambda i, j: (i, 0)),
-            pl.BlockSpec((bk, wp_), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, bk), lambda i, j: (0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((np_, 1), jnp.int32),
-            jax.ShapeDtypeStruct((np_, 1), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bn, 1), jnp.int32),
-            pltpu.VMEM((bn, 1), jnp.int32),
-        ],
-        compiler_params=_PARAMS,
-        cost_estimate=autotune.cost_hamming_packed(np_, kp_, wp_),
-        interpret=interpret,
-    )(xp, cp, vp[None, :])
-    return lab[:n, 0], dist[:n, 0]
+    return _hamming_call(packed.astype(jnp.uint32),
+                         packed_centers.astype(jnp.uint32), center_valid,
+                         kind="hamming_packed", packed_bits=bits,
+                         bn=bn, bk=bk, interpret=interpret)

@@ -1,121 +1,58 @@
 """Backend-aware public wrappers around the Pallas kernels.
 
-Dispatch policy (per-process, decided from the actual JAX backend):
-  - tpu   : compiled Pallas kernels.
-  - cpu   : interpret mode — executes the kernel body in Python with
-            bit-identical semantics, so the ref-vs-kernel allclose tests
-            are meaningful on CPU (this container, unit tests).
-  - other : the kernels are written against `pallas.tpu`; running them in
-            interpret mode on a GPU would silently execute Python-speed
-            loops on device buffers. Fall back to the blocked jnp paths
-            instead, with a one-time warning.
+Dispatch policy (per process, decided from the actual JAX backend):
+  - tpu : compiled Pallas kernels.
+  - cpu : interpret mode — executes the kernel body with bit-identical
+          semantics, so the ref-vs-kernel tests are meaningful on CPU.
+Any other backend is refused with a named error: the kernels are written
+against ``pallas.tpu``, and no blocked-jnp stand-in may hide which device
+ran them.
 """
 from __future__ import annotations
 
-import warnings
-
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import distance_argmin as _da
 from repro.kernels import flash_attention as _fa
-from repro.kernels import minhash_buckets as _mh
-
-_KERNEL_KW = ("bn", "bk", "chunk", "bq", "bb", "interpret")
-_warned = False
 
 
-def _mode() -> str:
+class UnsupportedBackendError(RuntimeError):
+    """A Pallas kernel was called on a backend that is neither TPU nor CPU."""
+
+
+def _interpret() -> bool:
     backend = jax.default_backend()
     if backend == "tpu":
-        return "compiled"
+        return False
     if backend == "cpu":
-        return "interpret"
-    return "fallback"
-
-
-def _warn_fallback(backend: str) -> None:
-    global _warned
-    if not _warned:
-        _warned = True
-        warnings.warn(
-            f"repro.kernels: backend {backend!r} is not TPU — pltpu kernels "
-            "would run in Python interpret mode; using the jnp fallback "
-            "paths instead.", RuntimeWarning, stacklevel=3)
-
-
-def _strip_kernel_kw(kw: dict) -> dict:
-    return {k: v for k, v in kw.items() if k not in _KERNEL_KW}
+        return True
+    raise UnsupportedBackendError(
+        f"repro.kernels: backend {backend!r} is not supported — the "
+        "kernels compile for TPU and run in interpret mode on CPU only")
 
 
 def distance_argmin_l2(x, centers, center_valid, **kw):
-    mode = _mode()
-    if mode == "fallback":
-        _warn_fallback(jax.default_backend())
-        from repro.core import assign as _assign
-        accumulate = kw.pop("accumulate", False)
-        kw = _strip_kernel_kw(kw)
-        if accumulate:
-            return _assign.assign_l2_with_partials(x, centers, center_valid,
-                                                   **kw)
-        return _assign.assign_l2(x, centers, center_valid, **kw)
-    kw.setdefault("interpret", mode == "interpret")
+    kw.setdefault("interpret", _interpret())
     return _da.distance_argmin_l2(x, centers, center_valid, **kw)
 
 
 def distance_argmin_hamming(codes, centers, center_valid, **kw):
-    mode = _mode()
-    if mode == "fallback":
-        _warn_fallback(jax.default_backend())
-        from repro.core import assign as _assign
-        lab, dist = _assign.assign_hamming(codes, centers, center_valid,
-                                           **_strip_kernel_kw(kw))
-        return lab, dist.astype(jnp.int32)
-    kw.setdefault("interpret", mode == "interpret")
+    kw.setdefault("interpret", _interpret())
     return _da.distance_argmin_hamming(codes, centers, center_valid, **kw)
 
 
 def distance_argmin_hamming_packed(packed, packed_centers, center_valid,
                                    *, bits, **kw):
-    mode = _mode()
-    if mode == "fallback":
-        _warn_fallback(jax.default_backend())
-        from repro.core import assign as _assign
-        lab, dist = _assign.assign_hamming_packed(
-            packed, packed_centers, center_valid, bits=bits,
-            **_strip_kernel_kw(kw))
-        return lab, dist.astype(jnp.int32)
-    kw.setdefault("interpret", mode == "interpret")
+    kw.setdefault("interpret", _interpret())
     return _da.distance_argmin_hamming_packed(packed, packed_centers,
                                               center_valid, bits=bits, **kw)
 
 
-def minhash_even_buckets(ids, keys, **kw):
-    mode = _mode()
-    if mode == "fallback":
-        _warn_fallback(jax.default_backend())
-        from repro.kernels import ref as _ref
-        return _ref.minhash_even_buckets_ref(ids, keys)
-    kw.setdefault("interpret", mode == "interpret")
-    return _mh.minhash_even_buckets(ids, keys, **kw)
-
-
 def flash_attention(q, k, v, **kw):
-    mode = _mode()
-    if mode == "fallback":
-        _warn_fallback(jax.default_backend())
-        from repro.kernels import ref as _ref
-        causal = kw.get("causal", True)
-        return _ref.attention_ref(q, k, v, causal=causal)
-    kw.setdefault("interpret", mode == "interpret")
+    kw.setdefault("interpret", _interpret())
     return _fa.flash_attention(q, k, v, **kw)
 
 
 def flash_centroid_attention(q, centers, v_cent, log_mass, **kw):
-    mode = _mode()
-    if mode == "fallback":
-        _warn_fallback(jax.default_backend())
-        from repro.kernels import ref as _ref
-        return _ref.centroid_attention_ref(q, centers, v_cent, log_mass)
-    kw.setdefault("interpret", mode == "interpret")
+    kw.setdefault("interpret", _interpret())
     return _fa.flash_centroid_attention(q, centers, v_cent, log_mass, **kw)

@@ -4,8 +4,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.utils.hashing import hash_u32, mix_u32
-
 
 def distance_argmin_l2_ref(x, centers, center_valid):
     x = x.astype(jnp.float32)
@@ -31,21 +29,12 @@ def distance_argmin_hamming_packed_ref(packed, packed_centers, center_valid,
     return jnp.argmin(dist, -1).astype(jnp.int32), jnp.min(dist, -1)
 
 
-def minhash_even_buckets_ref(ids, keys):
-    """ids: (nb, bsz) int32, keys: (K, 2) uint32 -> (nb,) uint32."""
-    sig = jnp.zeros((ids.shape[0],), jnp.uint32)
-    for k in range(keys.shape[0]):
-        h = hash_u32(ids, keys[k, 0], keys[k, 1])
-        sig = mix_u32(sig, jnp.min(h, axis=-1))
-    return sig
-
-
 def centroid_attention_ref(q, centers, v_cent, log_mass):
     """q: (B,Hq,S,dh); centers/v_cent: (B,Hkv,K,dh); log_mass: (B,Hkv,K).
 
     Mass-weighted non-causal softmax over centroids (GQA by repetition);
     ``log_mass = -1e30`` rows are effectively excluded. The oracle for
-    ``flash_centroid_attention`` and the CPU/GPU fallback path.
+    ``flash_centroid_attention``.
     """
     B, Hq, S, dh = q.shape
     Hkv = centers.shape[1]

@@ -86,10 +86,12 @@ def main() -> None:
                     help="max reservoir rows for streamed/sharded discovery "
                          "(default: all rows -> bit-identical to in-core)")
     ap.add_argument("--compare", action="store_true")
-    from repro.utils.platform import add_platform_args, apply_platform_args
+    from repro.utils.platform import (add_platform_args, apply_platform_args,
+                                      enable_compile_cache)
     add_platform_args(ap)
     args = ap.parse_args()
     apply_platform_args(args)          # before the first JAX computation
+    enable_compile_cache()
     if args.streaming and args.distributed:
         raise SystemExit("--streaming and --distributed are exclusive")
     if args.mesh and args.distributed:

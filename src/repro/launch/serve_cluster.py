@@ -159,10 +159,12 @@ def main() -> None:
                     help="run a RefitAutopilot: reservoir served traffic "
                          "and refit-validate-publish on this period")
     ap.add_argument("--smoke", action="store_true")
-    from repro.utils.platform import add_platform_args, apply_platform_args
+    from repro.utils.platform import (add_platform_args, apply_platform_args,
+                                      enable_compile_cache)
     add_platform_args(ap)
     args = ap.parse_args()
     apply_platform_args(args)          # before the first JAX computation
+    enable_compile_cache()
 
     import jax
     import numpy as np

@@ -22,6 +22,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -35,7 +36,6 @@ from repro.models import init_params, param_specs, train_loss
 from repro.models import model as MODEL
 from repro.models.sharding import activation_sharding
 from repro.optim import adamw, clip_by_global_norm, warmup_cosine
-from repro.utils.compat import shard_map
 
 
 def build_mesh(spec: str | None):

@@ -1,4 +1,4 @@
-"""Shared utilities (hashing, JAX-version shims, platform config).
+"""Shared utilities (hashing, mesh helper, platform config).
 
 Deliberately import-light: ``repro.utils.platform`` must be importable
 BEFORE the JAX backend initializes (its whole job is setting XLA flags

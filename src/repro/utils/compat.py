@@ -1,27 +1,16 @@
-"""Version compatibility shims + mesh construction helpers.
+"""Mesh construction helper shared by every sharded entry point.
 
-`jax.shard_map` (with `check_vma`) only exists on newer JAX; older
-releases ship `jax.experimental.shard_map.shard_map` (with `check_rep`).
-Same story for `jax.lax.axis_size`. Everything in this repo goes
-through these wrappers so both work.
-
-``make_mesh`` is the one-liner every sharded entry point and launch
-driver shares: a 1-axis ``Mesh`` over all local devices, named per the
-repo's mesh/axis convention (docs/architecture.md — data-parallel axis
-is called ``"data"`` unless a caller says otherwise).
+``make_mesh`` is the one-liner the launch CLIs, the sharded fit and
+the sharded predict share: a 1-axis ``Mesh`` over all local devices,
+named per the repo's mesh/axis convention (docs/architecture.md — the
+data-parallel axis is called ``"data"`` unless a caller says otherwise).
+``shard_map`` and ``axis_size`` come straight from ``jax`` /
+``jax.lax``; the installed JAX is the floor.
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a mapped mesh axis (usable for shapes/asserts)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
 
 
 def make_mesh(axis: str = "data", devices=None) -> "jax.sharding.Mesh":
@@ -44,14 +33,3 @@ def make_mesh(axis: str = "data", devices=None) -> "jax.sharding.Mesh":
     from jax.sharding import Mesh
     return Mesh(np.array(devices if devices is not None
                          else jax.devices()), (axis,))
-
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)

@@ -8,11 +8,13 @@ effect: XLA reads them at backend initialization, so they must be set
 before the first JAX computation (importing ``jax`` is fine — backends
 initialize lazily on first device use).
 
-Three entry points:
+Four entry points:
 
 - ``set_platform(platform, host_device_count=)`` — process-wide setup
   for CLI ``main()``s (call before any JAX op; raises if the backend is
   already live and the request cannot take effect).
+- ``enable_compile_cache()`` — JAX's persistent compilation cache at
+  ``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``.
 - ``host_device_env(n, base=)`` — a merged environment dict for
   *subprocess* launches (bench_scaling, the distributed tests), so
   child processes get the flag without string surgery at call sites.
@@ -24,7 +26,7 @@ from __future__ import annotations
 
 import argparse
 import os
-import warnings
+from pathlib import Path
 
 _FORCE_FLAG = "--xla_force_host_platform_device_count"
 
@@ -92,9 +94,11 @@ def set_platform(platform: str | None = None, *,
 
     Parameters
     ----------
-    platform : {"cpu", "gpu", "tpu"} or None
-        Target platform (``jax.config.jax_platform_name``); None keeps
-        the default resolution order.
+    platform : {"cpu", "tpu"} or None
+        Target platform, set as ``jax_platforms`` so JAX initializes that
+        backend only — a missing TPU then fails at the first computation
+        instead of quietly running on the CPU. None keeps the default
+        resolution order.
     host_device_count : int or None
         Force this many fake host devices (the multi-device CPU story:
         ``XLA_FLAGS=--xla_force_host_platform_device_count=n``). None
@@ -104,7 +108,8 @@ def set_platform(platform: str | None = None, *,
     ------
     RuntimeError
         If ``host_device_count`` is requested after the backend
-        initialized with a different device count.
+        initialized with a different device count, or ``platform`` after
+        the backend initialized on another platform.
     """
     if host_device_count is not None:
         n = int(host_device_count)
@@ -121,14 +126,37 @@ def set_platform(platform: str | None = None, *,
         os.environ["XLA_FLAGS"] = _merge_xla_flags(
             os.environ.get("XLA_FLAGS", ""), n)
     if platform is not None:
-        if platform not in ("cpu", "gpu", "tpu"):
+        if platform not in ("cpu", "tpu"):
             raise ValueError(f"unknown platform {platform!r} "
-                             "(expected cpu/gpu/tpu)")
+                             "(expected cpu/tpu)")
         import jax
-        try:
-            jax.config.update("jax_platform_name", platform)
-        except Exception as e:  # pragma: no cover - jax-version specific
-            warnings.warn(f"could not set jax_platform_name: {e}")
+        if _backend_initialized():
+            live = jax.default_backend()
+            if live != platform:
+                raise RuntimeError(
+                    f"set_platform({platform!r}) after the JAX backend "
+                    f"initialized on {live!r} — call it before the first "
+                    f"JAX computation, or export JAX_PLATFORMS={platform}")
+            return
+        jax.config.update("jax_platforms", platform)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing else is set here. Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` (listed in ``.gitignore``), so a later run
+    from the same checkout finds what an earlier one compiled; a
+    per-process or temporary directory would never be found again.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def worker_devices(n: int | None = None) -> tuple:
@@ -172,7 +200,7 @@ def worker_devices(n: int | None = None) -> tuple:
 def add_platform_args(parser: argparse.ArgumentParser) -> None:
     """Install the shared ``--platform`` / ``--host-devices`` flags."""
     parser.add_argument("--platform", default=None,
-                        choices=["cpu", "gpu", "tpu"],
+                        choices=["cpu", "tpu"],
                         help="JAX platform (default: jax's own resolution)")
     parser.add_argument("--host-devices", type=int, default=None,
                         help="force this many fake host (CPU) devices — "

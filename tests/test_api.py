@@ -17,6 +17,8 @@ Multi-device sharding is covered by tests/test_distributed.py; here
 sharded paths run on a 1-device mesh, which exercises the same
 shard_map code.
 """
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -98,20 +100,43 @@ def test_bare_array_means_dense_and_tuples_rejected():
 # Pluggable seeders
 # ---------------------------------------------------------------------------
 
-def test_kmeanspp_seeder_matches_seed_then_assign():
+@pytest.mark.parametrize("k_max", [16, 64])
+def test_kmeanspp_seeder_matches_seed_then_assign(k_max):
     """GEEK(cfg, seeder=KMeansPPSeeder(k)) ≡ baselines.seed_then_assign
     on the same fixed key — the facade hands non-bucket seeders the
-    whole fit key, so the D^2 draws are identical."""
+    whole fit key, so the D^2 draws are identical.
+
+    With k_max = k both sides run the same (n, k) distance program, and
+    dists are bit-identical. A wider center table (invalid rows masked)
+    is another dot program, whose x·c may sum in another order: labels
+    and centers stay exact, dists agree to f32 rounding."""
     d = _dense()
     k = 16
     key = jax.random.PRNGKey(7)
-    est = GEEK(CFG, seeder=KMeansPPSeeder(k))
+    est = GEEK(dataclasses.replace(CFG, k_max=k_max),
+               seeder=KMeansPPSeeder(k))
     model = est.fit(DenseData(d.x), key)
     base = baselines.seed_then_assign(d.x, k, key)
+    np.testing.assert_array_equal(np.asarray(model.centers)[:k],
+                                  np.asarray(base.centers))
+    assert not np.asarray(model.center_valid)[k:].any()
     np.testing.assert_array_equal(np.asarray(est.result_.labels),
                                   np.asarray(base.labels))
-    np.testing.assert_allclose(np.asarray(est.result_.dists),
-                               np.asarray(base.dists), rtol=0, atol=0)
+    dists, base_dists = np.asarray(est.result_.dists), np.asarray(base.dists)
+    if k_max == k:
+        np.testing.assert_array_equal(dists, base_dists)
+    else:
+        # each x·c is within γ_d·|x||c| of exact (γ_d = d·u / (1 - d·u),
+        # u = 2^-24) and the squared distance carries -2·x·c, so two
+        # summation orders differ by at most 4·γ_d·|x||c|; rtol covers
+        # the sqrt and the squaring back
+        u = np.finfo(np.float32).eps / 2
+        dim = d.x.shape[1]
+        gamma = dim * u / (1 - dim * u)
+        bound = (4 * gamma * np.linalg.norm(np.asarray(d.x), axis=1).max()
+                 * np.linalg.norm(np.asarray(base.centers), axis=1).max())
+        np.testing.assert_allclose(np.square(dists), np.square(base_dists),
+                                   rtol=4 * u, atol=bound)
     assert int(est.result_.k_star) == k
     assert model.seeder_id == "kmeans++"
 

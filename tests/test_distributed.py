@@ -26,7 +26,7 @@ def test_compressed_psum_approximates_mean():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.distributed.compression import compressed_psum
-        from repro.utils.compat import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(jax.devices()), ("d",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 500))
         f = shard_map(lambda xs: compressed_psum(xs[0], "d")[0][None],
@@ -46,7 +46,7 @@ def test_error_feedback_removes_bias():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.distributed.compression import compressed_psum
-        from repro.utils.compat import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(jax.devices()), ("d",))
         # same tiny gradient every step: with error feedback the running sum
         # of compressed means must track the true accumulation
@@ -139,7 +139,7 @@ def test_ddp_compress_matches_pjit_direction():
         from repro.configs import get_arch
         from repro.distributed.compression import compressed_psum_tree
         from repro.models import init_params, train_loss
-        from repro.utils.compat import shard_map
+        from jax import shard_map
         cfg = get_arch("smollm_360m", smoke=True)
         mesh = Mesh(np.array(jax.devices()), ("data",))
         params = init_params(cfg, jax.random.PRNGKey(0))
@@ -178,7 +178,7 @@ def test_sharded_pallas_assign_matches_single_device():
         from repro.core.distributed import _assign_l2_accumulate
         from repro.core.geek import GeekConfig
         from repro.kernels import ops as kops
-        from repro.utils.compat import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(jax.devices()), ("data",))
         key = jax.random.PRNGKey(0)
         x = jax.random.normal(key, (1024, 32))

@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, pack, ref
-from repro.utils.hashing import derive_hash_keys
 
 
 @pytest.mark.parametrize("n,k,d", [(64, 8, 16), (130, 33, 70), (257, 128, 128),
@@ -32,7 +31,7 @@ def test_distance_argmin_hamming_sweep(n, k, d, card):
     codes = jax.random.randint(key, (n, d), 0, card)
     c = jax.random.randint(jax.random.fold_in(key, 1), (k, d), 0, card)
     valid = jnp.ones((k,), bool)
-    lk, dk = ops.distance_argmin_hamming(codes, c, valid, bn=32, bk=8, chunk=16)
+    lk, dk = ops.distance_argmin_hamming(codes, c, valid, bn=32, bk=8)
     lr, dr = ref.distance_argmin_hamming_ref(codes, c, valid)
     np.testing.assert_array_equal(np.array(dk), np.array(dr))
     np.testing.assert_array_equal(np.array(lk), np.array(lr))
@@ -42,7 +41,7 @@ def test_distance_argmin_hamming_sweep(n, k, d, card):
                                         (64, 8, 400, 16), (33, 70, 7, 2)])
 def test_distance_argmin_hamming_packed_sweep(n, k, d, bits):
     """Packed kernel vs the *unpacked* equality oracle: labels and counts
-    bit-identical. Shapes include k < bk, ragged d, d not a chunk multiple."""
+    bit-identical. Shapes include k < bk and ragged d."""
     rng = np.random.default_rng(n * k + bits)
     card = 1 << bits
     codes = jnp.asarray(rng.integers(0, card, (n, d)), jnp.int32)
@@ -51,7 +50,7 @@ def test_distance_argmin_hamming_packed_sweep(n, k, d, bits):
     xp = pack.pack_codes(codes, bits)
     cp = pack.pack_codes(c, bits)
     lk, dk = ops.distance_argmin_hamming_packed(xp, cp, valid, bits=bits,
-                                                bn=32, bk=128, chunk=8)
+                                                bn=32, bk=128)
     lr, dr = ref.distance_argmin_hamming_ref(codes, c, valid)
     np.testing.assert_array_equal(np.array(dk), np.array(dr))
     np.testing.assert_array_equal(np.array(lk), np.array(lr))
@@ -62,7 +61,7 @@ def test_distance_argmin_hamming_packed_sweep(n, k, d, bits):
 
 @pytest.mark.parametrize("kernel", ["l2", "hamming", "packed"])
 def test_distance_argmin_autotuned_tiles(kernel):
-    """No explicit bn/bk/chunk: the shape-keyed autotuner picks the tiles
+    """No explicit bn/bk: the shape-keyed autotuner picks the tiles
     and the kernels still match the oracles on ragged shapes."""
     key = jax.random.PRNGKey(11)
     for n, k, d in [(37, 3, 5), (300, 65, 129), (128, 260, 48)]:
@@ -106,15 +105,6 @@ def test_distance_argmin_l2_accumulate(n, k, d):
     np.testing.assert_allclose(np.array(sums), np.array(seg_s),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(np.array(cnt), np.array(seg_c))
-
-
-@pytest.mark.parametrize("nb,bsz,K", [(10, 8, 1), (100, 64, 3), (33, 17, 5)])
-def test_minhash_even_buckets_sweep(nb, bsz, K, rng):
-    ids = jax.random.randint(rng, (nb, bsz), 0, 1 << 20)
-    keys = derive_hash_keys(jax.random.fold_in(rng, K), (K,))
-    sk = ops.minhash_even_buckets(ids, keys, bb=16)
-    sr = ref.minhash_even_buckets_ref(ids, keys)
-    np.testing.assert_array_equal(np.array(sk), np.array(sr))
 
 
 @pytest.mark.parametrize("B,Hq,Hkv,S,dh", [(1, 4, 4, 128, 32),

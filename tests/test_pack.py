@@ -119,11 +119,11 @@ def test_assign_hamming_onehot_labels_bit_identical(card):
 def test_select_tiles_fits_budget(kind, n, k, d):
     tc = autotune.select_tiles(kind, n, k, d)
     assert tc.bn >= 8 and tc.bk >= 8
-    if kind == "l2":
-        assert tc.chunk == 0
-    else:
-        assert tc.chunk >= 8
-    used = autotune._vmem_bytes(kind, tc.bn, tc.bk, max(tc.chunk, 1), d, 4)
+    # bk is a lane axis on the chip: 128-aligned or one tile over all of k
+    assert tc.bk % 128 == 0 or tc.bk >= k
+    if kind != "l2":
+        assert tc.bn * tc.bk <= autotune.VPU_TILE_ELEMS
+    used = autotune._vmem_bytes(kind, tc.bn, tc.bk, d, 4)
     assert used <= autotune.DEFAULT_BUDGET
 
 
@@ -131,7 +131,7 @@ def test_select_tiles_deterministic_and_cached():
     a = autotune.select_tiles("l2", 4096, 256, 64)
     b = autotune.select_tiles("l2", 4096, 256, 64)
     assert a is b  # lru_cache hit
-    assert a == autotune.TileConfig(a.bn, a.bk, a.chunk)
+    assert a == autotune.TileConfig(a.bn, a.bk)
 
 
 def test_select_tiles_huge_d_still_resolves():
