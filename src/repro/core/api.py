@@ -446,12 +446,15 @@ def discover(kind: str, parts: tuple, key: jax.Array, cfg: GeekConfig,
         # no LSH keys drawn: the seeder owns the whole fit key, which is
         # what makes KMeansPPSeeder reproduce seed_then_assign(x, k, key)
         tkey, bkeys, skey = key, None, key
-    transform = bucketer.fit_transform(kind, parts, tkey, cfg,
-                                       boundaries=boundaries)
-    space = transform(*parts) if code is None else code(transform, parts)
-    buckets = (bucketer.buckets(kind, space, bkeys, cfg)
-               if bkeys is not None else None)
-    seeds, overflow = seeder.seed(space, buckets, skey, cfg)
+    with jax.named_scope("geek.transform"):
+        transform = bucketer.fit_transform(kind, parts, tkey, cfg,
+                                           boundaries=boundaries)
+        space = transform(*parts) if code is None else code(transform, parts)
+    with jax.named_scope("geek.bucket"):
+        buckets = (bucketer.buckets(kind, space, bkeys, cfg)
+                   if bkeys is not None else None)
+    with jax.named_scope("geek.silk"):
+        seeds, overflow = seeder.seed(space, buckets, skey, cfg)
     return transform, space, seeds, overflow
 
 
@@ -465,11 +468,15 @@ def _fit_incore(present: tuple, key: jax.Array, *, cfg: GeekConfig,
     parts = _reinsert_none(present, none_pattern)
     transform, space, seeds, overflow = discover(kind, parts, key, cfg,
                                                  bucketer, seeder)
-    model = assigner.build(space, seeds, cfg, metric=bucketer.metric(kind),
-                           bits=bucketer.code_bits(kind, parts, cfg),
-                           transform=transform, bucketer_id=bucketer.name,
-                           seeder_id=seeder.name)
-    labels, dists = assigner.assign(model, space)
+    with jax.named_scope("geek.centers"):
+        model = assigner.build(space, seeds, cfg,
+                               metric=bucketer.metric(kind),
+                               bits=bucketer.code_bits(kind, parts, cfg),
+                               transform=transform,
+                               bucketer_id=bucketer.name,
+                               seeder_id=seeder.name)
+    with jax.named_scope("geek.assign"):
+        labels, dists = assigner.assign(model, space)
     radius = assign_mod.cluster_radius(dists, labels, cfg.k_max)
     result = GeekResult(labels, dists, model.centers, model.center_valid,
                         seeds.k_star, radius, seeds, overflow)
@@ -489,10 +496,13 @@ def _seed_reservoir(present: tuple, boundaries, key: jax.Array, *,
     transform, space, seeds, overflow = discover(kind, parts, key, cfg,
                                                  bucketer, seeder,
                                                  boundaries=boundaries)
-    model = assigner.build(space, seeds, cfg, metric=bucketer.metric(kind),
-                           bits=bucketer.code_bits(kind, parts, cfg),
-                           transform=transform, bucketer_id=bucketer.name,
-                           seeder_id=seeder.name)
+    with jax.named_scope("geek.centers"):
+        model = assigner.build(space, seeds, cfg,
+                               metric=bucketer.metric(kind),
+                               bits=bucketer.code_bits(kind, parts, cfg),
+                               transform=transform,
+                               bucketer_id=bucketer.name,
+                               seeder_id=seeder.name)
     return model, seeds, overflow
 
 
@@ -609,13 +619,15 @@ def _build_fit_sharded(mesh, cfg: GeekConfig, kind: str, axis: str,
                                           seeds.valid, axis)
             local_seeds = seeds._replace(
                 id=jnp.arange(space_sel.shape[0], dtype=jnp.int32))
-            model = assigner.build(space_sel, local_seeds, cfg,
-                                   metric=bucketer.metric(kind),
-                                   bits=bucketer.code_bits(kind, parts, cfg),
-                                   transform=transform,
-                                   bucketer_id=bucketer.name,
-                                   seeder_id=seeder.name)
-            labels, dists = assigner.assign(model, space_local)
+            with jax.named_scope("geek.centers"):
+                model = assigner.build(
+                    space_sel, local_seeds, cfg,
+                    metric=bucketer.metric(kind),
+                    bits=bucketer.code_bits(kind, parts, cfg),
+                    transform=transform, bucketer_id=bucketer.name,
+                    seeder_id=seeder.name)
+            with jax.named_scope("geek.assign"):
+                labels, dists = assigner.assign(model, space_local)
             radius = jax.lax.pmax(
                 assign_mod.cluster_radius(dists, labels, cfg.k_max), axis)
             model = dataclasses.replace(model, radius=radius)
@@ -660,14 +672,18 @@ def _build_fit_sharded(mesh, cfg: GeekConfig, kind: str, axis: str,
         # the SAME discover() as the in-core and streaming bodies
         transform, space_res, seeds, overflow = discover(
             kind, disc_parts, key, cfg, bucketer, seeder, code=code)
-        space_local = local_codes[0] if local_codes else transform(*parts)
-        model = assigner.build(space_res, seeds, cfg,
-                               metric=bucketer.metric(kind),
-                               bits=bucketer.code_bits(kind, parts, cfg),
-                               transform=transform,
-                               bucketer_id=bucketer.name,
-                               seeder_id=seeder.name)
-        labels, dists = assigner.assign(model, space_local)
+        with jax.named_scope("geek.transform"):
+            space_local = (local_codes[0] if local_codes
+                           else transform(*parts))
+        with jax.named_scope("geek.centers"):
+            model = assigner.build(space_res, seeds, cfg,
+                                   metric=bucketer.metric(kind),
+                                   bits=bucketer.code_bits(kind, parts, cfg),
+                                   transform=transform,
+                                   bucketer_id=bucketer.name,
+                                   seeder_id=seeder.name)
+        with jax.named_scope("geek.assign"):
+            labels, dists = assigner.assign(model, space_local)
         radius = jax.lax.pmax(
             assign_mod.cluster_radius(dists, labels, cfg.k_max), axis)
         model = dataclasses.replace(model, radius=radius)

@@ -399,38 +399,43 @@ def discover_sharded(kind: str, parts: tuple, key, cfg: GeekConfig,
                                     signature_partition_slice)
     from repro.core.geek import _code_items
     tkey, bkeys, skey = bucketer.split_key(kind, key)
-    transform = fit_transform_sharded(kind, parts, tkey, cfg, axis, n)
-    space_local = transform(*parts)                      # (nl, d')
+    with jax.named_scope("geek.transform"):
+        transform = fit_transform_sharded(kind, parts, tkey, cfg, axis, n)
+        space_local = transform(*parts)                  # (nl, d')
 
-    if kind == "dense":
-        (k_proj,) = bkeys
-        a = lsh.qalsh_projections(k_proj, space_local.shape[1], cfg.m,
-                                  dtype=space_local.dtype)
-        h_local = lsh.qalsh_hash(space_local, a)         # (nl, m)
-        h_cols = exchange_columns(h_local, axis, n)      # (n, m_pad/g)
-        ids_t, seg_t, b_of_id, sizes = rank_partition_slice(h_cols, cfg.t)
-        num_tables, cap_t = cfg.m, cfg.t
-    else:
-        k_item, k_sig = bkeys
-        items = _code_items(space_local, k_item)
-        sig_keys = derive_hash_keys(k_sig, (cfg.bucket_l, cfg.bucket_k))
-        sigs = lsh.minhash_signatures(items, jnp.ones_like(items, bool),
-                                      sig_keys)          # (L, nl)
-        sig_rows = exchange_rows(sigs, axis, n)          # (L_pad/g, n)
-        ids_t, seg_t, b_of_id, sizes = signature_partition_slice(sig_rows)
-        num_tables, cap_t = cfg.bucket_l, n
+    with jax.named_scope("geek.bucket"):
+        if kind == "dense":
+            (k_proj,) = bkeys
+            a = lsh.qalsh_projections(k_proj, space_local.shape[1], cfg.m,
+                                      dtype=space_local.dtype)
+            h_local = lsh.qalsh_hash(space_local, a)     # (nl, m)
+            h_cols = exchange_columns(h_local, axis, n)  # (n, m_pad/g)
+            ids_t, seg_t, b_of_id, sizes = rank_partition_slice(h_cols,
+                                                                cfg.t)
+            num_tables, cap_t = cfg.m, cfg.t
+        else:
+            k_item, k_sig = bkeys
+            items = _code_items(space_local, k_item)
+            sig_keys = derive_hash_keys(k_sig, (cfg.bucket_l, cfg.bucket_k))
+            sigs = lsh.minhash_signatures(items, jnp.ones_like(items, bool),
+                                          sig_keys)      # (L, nl)
+            sig_rows = exchange_rows(sigs, axis, n)      # (L_pad/g, n)
+            ids_t, seg_t, b_of_id, sizes = signature_partition_slice(
+                sig_rows)
+            num_tables, cap_t = cfg.bucket_l, n
 
-    # mask pad tables before shipping the bucket map back to id owners
-    mt = b_of_id.shape[0]
-    gt = jax.lax.axis_index(axis) * mt + jnp.arange(mt, dtype=jnp.int32)
-    b_of_id = jnp.where((gt < num_tables)[:, None], b_of_id, cap_t)
-    bins_rows = scatter_table_rows(b_of_id, axis, cap_t,
-                                   cfg.compress_collectives)  # (T_pad, nl)
+        # mask pad tables before shipping the bucket map back to id owners
+        mt = b_of_id.shape[0]
+        gt = jax.lax.axis_index(axis) * mt + jnp.arange(mt, dtype=jnp.int32)
+        b_of_id = jnp.where((gt < num_tables)[:, None], b_of_id, cap_t)
+        bins_rows = scatter_table_rows(b_of_id, axis, cap_t,
+                                       cfg.compress_collectives)  # (T_pad, nl)
 
-    seeds, overflow = silk_seeding_sharded(ids_t, seg_t, sizes, bins_rows,
-                                           skey, cfg, axis, n=n,
-                                           num_tables=num_tables,
-                                           cap_t=cap_t)
+    with jax.named_scope("geek.silk"):
+        seeds, overflow = silk_seeding_sharded(ids_t, seg_t, sizes,
+                                               bins_rows, skey, cfg, axis,
+                                               n=n, num_tables=num_tables,
+                                               cap_t=cap_t)
     return transform, space_local, seeds, overflow
 
 

@@ -193,7 +193,8 @@ def _assign_chunk_fn(donate: bool, mesh=None, axis: str = "data",
     """
     def chunk_body(model: GeekModel, parts: tuple, k_max: int):
         """One streamed step: labels/dists for a chunk + partial radius."""
-        labels, dists = assigner.assign(model, model.encode(*parts))
+        with jax.named_scope("geek.assign"):
+            labels, dists = assigner.assign(model, model.encode(*parts))
         radius = assign_mod.cluster_radius(dists, labels, k_max)
         return labels, dists, radius
 
@@ -205,7 +206,8 @@ def _assign_chunk_fn(donate: bool, mesh=None, axis: str = "data",
         """Sharded chunk step: shard rows, assign, pmax the radius."""
         def body(model, parts):
             """Per-device encode+assign on this device's row shard."""
-            labels, dists = assigner.assign(model, model.encode(*parts))
+            with jax.named_scope("geek.assign"):
+                labels, dists = assigner.assign(model, model.encode(*parts))
             radius = jax.lax.pmax(
                 assign_mod.cluster_radius(dists, labels, k_max), axis)
             return labels, dists, radius

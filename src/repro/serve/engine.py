@@ -50,6 +50,7 @@ import numpy as np
 from repro.core.model import (GeekModel, patch_probed_fallback, predict,
                               predict_probed)
 from repro.serve.registry import ModelRegistry, _transform_kind
+from repro.utils import spans
 
 #: queue sentinel shutting the worker down
 _CLOSE = object()
@@ -85,12 +86,13 @@ def _exact_step(n_parts: int, donate: bool):
     alternate in place. CPU ignores donation, so we don't request it
     there (avoids a warning per call).
     """
-    def body(model, *parts):
+    def serve_exact(model, *parts):
         """Encode raw parts and assign in one traced program."""
-        return predict(model, model.encode(*parts))
+        with jax.named_scope("geek.serve"):
+            return predict(model, model.encode(*parts))
     kwargs = {"donate_argnums": tuple(range(1, 1 + n_parts))} if donate \
         else {}
-    return jax.jit(body, **kwargs)
+    return jax.jit(serve_exact, **kwargs)
 
 
 @functools.lru_cache(maxsize=None)
@@ -103,10 +105,11 @@ def _probed_step(n_parts: int, probes: int):
     """
     del n_parts  # arity only keys the cache alongside probes
 
-    def body(model, *parts):
+    def serve_probed(model, *parts):
         """Encode raw parts and probe the center index in one program."""
-        return predict_probed(model, model.encode(*parts), probes)
-    return jax.jit(body, static_argnames=())
+        with jax.named_scope("geek.serve"):
+            return predict_probed(model, model.encode(*parts), probes)
+    return jax.jit(serve_probed, static_argnames=())
 
 
 def pad_ladder(max_batch: int, *, min_bucket: int = 64,
@@ -184,15 +187,22 @@ class Assignment:
 
 
 class _Request:
-    """A queued submit: host-side parts + the future to resolve."""
+    """A queued submit: host-side parts + the future to resolve.
 
-    __slots__ = ("parts", "n", "future", "t_submit")
+    ``req`` is the request's span id and ``t_ns`` its submit time on the
+    profiler's clock, both stamped only while a profiler session is
+    live (else -1 and 0; ``repro.utils.spans``).
+    """
 
-    def __init__(self, parts, n, future, t_submit):
+    __slots__ = ("parts", "n", "future", "t_submit", "req", "t_ns")
+
+    def __init__(self, parts, n, future, t_submit, req=-1, t_ns=0):
         self.parts = parts
         self.n = n
         self.future = future
         self.t_submit = t_submit
+        self.req = req
+        self.t_ns = t_ns
 
 
 class ClusterServer:
@@ -391,7 +401,13 @@ class ClusterServer:
         fut: Future = Future()
         with self._stats_lock:
             self._stats["submitted"] += 1
-        self._queue.put(_Request(parts, n, fut, time.monotonic()))
+        req, t_ns = -1, 0
+        if spans.enabled():
+            # the front end's request id, or a fresh one for a direct caller
+            req, t_ns = spans.current_req(), time.time_ns()
+            if req < 0:
+                req = spans.new_id()
+        self._queue.put(_Request(parts, n, fut, time.monotonic(), req, t_ns))
         if self._fatal is not None and not fut.done():
             # lost the race with a concurrent worker death: the drain in
             # _fail may have missed this request, so resolve it here
@@ -553,9 +569,18 @@ class ClusterServer:
                 wait = None
                 # idle: don't sit on finished work while blocking open-ended
                 self._retire()
+            due = (time.time_ns() + int(wait * 1e9)
+                   if wait is not None and spans.enabled() else 0)
             try:
                 item = self._queue.get(timeout=wait)
             except queue.Empty:
+                item = None
+            if due:
+                # how late the worker ran again after a timed wait (an
+                # early wake, by a new request, is a zero-length span)
+                now = time.time_ns()
+                spans.interval("geek.engine.lag", min(due, now), now)
+            if item is None:
                 continue
             if item is _CLOSE:
                 closing = True
@@ -591,24 +616,34 @@ class ClusterServer:
             taken += take[-1].n
         if not take:  # can't happen while submit() bounds n; be safe
             return rows
+        batch = -1
+        if spans.enabled():
+            batch, t_take = spans.new_id(), time.time_ns()
+            for r in take:
+                if r.t_ns:
+                    spans.interval("geek.engine.queue", r.t_ns, t_take,
+                                   req=r.req, batch=batch)
         try:
-            # registry snapshot INSIDE the per-batch guard: a failing
-            # registry (or a poisoned record) fails this batch's futures
-            # and the worker keeps serving — it must never kill the loop
-            rec = self.registry.current(self.name)
-            host = tuple(
-                None if take[0].parts[i] is None else
-                np.concatenate([r.parts[i] for r in take], axis=0)
-                for i in range(self._arity))
-            finish = self._dispatch(self._on_device(rec), host, taken)
+            with spans.span("geek.engine.flush", batch=batch):
+                # registry snapshot INSIDE the per-batch guard: a failing
+                # registry (or a poisoned record) fails this batch's
+                # futures and the worker keeps serving — it must never
+                # kill the loop
+                rec = self.registry.current(self.name)
+                host = tuple(
+                    None if take[0].parts[i] is None else
+                    np.concatenate([r.parts[i] for r in take], axis=0)
+                    for i in range(self._arity))
+                finish = self._dispatch(self._on_device(rec), host, taken)
         except Exception as e:                  # noqa: BLE001 — per-batch
             for r in take:
                 r.future.set_exception(e)
             with self._stats_lock:
                 self._stats["failed"] += len(take)
             return rows - taken
+        t_issued = time.time_ns() if batch >= 0 else 0
         self._retire()
-        self._inflight = (take, taken, rec, finish)
+        self._inflight = (take, taken, rec, finish, batch, t_issued)
         with self._stats_lock:
             self._stats["batches"] += 1
             self._stats["flushes"][reason] += 1
@@ -681,25 +716,29 @@ class ClusterServer:
         """Resolve the previous micro-batch's futures (blocks on device)."""
         if self._inflight is None:
             return
-        take, taken, rec, finish = self._inflight
+        take, taken, rec, finish, batch, t_issued = self._inflight
         self._inflight = None
-        try:
-            labels, dists = finish()
-        except Exception as e:                  # noqa: BLE001 — per-batch
-            for r in take:
-                r.future.set_exception(e)
-            with self._stats_lock:
-                self._stats["failed"] += len(take)
-            return
-        off = 0
-        for r in take:
+        if t_issued:
+            spans.interval("geek.engine.held", t_issued, time.time_ns(),
+                           batch=batch)
+        with spans.span("geek.engine.retire", batch=batch):
             try:
-                r.future.set_result(Assignment(labels[off:off + r.n],
-                                               dists[off:off + r.n],
-                                               rec.version))
-            except InvalidStateError:
-                pass  # a submit/close race already failed this future
-            off += r.n
-        with self._stats_lock:
-            self._stats["completed"] += len(take)
-            self._stats["rows_served"] += taken
+                labels, dists = finish()
+            except Exception as e:              # noqa: BLE001 — per-batch
+                for r in take:
+                    r.future.set_exception(e)
+                with self._stats_lock:
+                    self._stats["failed"] += len(take)
+                return
+            off = 0
+            for r in take:
+                try:
+                    r.future.set_result(Assignment(labels[off:off + r.n],
+                                                   dists[off:off + r.n],
+                                                   rec.version))
+                except InvalidStateError:
+                    pass  # a submit/close race already failed this future
+                off += r.n
+            with self._stats_lock:
+                self._stats["completed"] += len(take)
+                self._stats["rows_served"] += taken

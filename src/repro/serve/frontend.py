@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.serve.engine import ServerClosedError, _KIND_ARITY
 from repro.serve.registry import _transform_kind
+from repro.utils import spans
 
 #: default wait on the engine future when the request carries no deadline
 DEFAULT_DEADLINE_S = 30.0
@@ -212,6 +213,11 @@ class _Handler(BaseHTTPRequestHandler):
     # -- endpoint bodies -----------------------------------------------------
 
     def _assign(self) -> None:
+        # the request's id is drawn here; submit and the engine carry it
+        with spans.span("geek.frontend.request", req=spans.NEW):
+            self._assign_request()
+
+    def _assign_request(self) -> None:
         fe = self.frontend
         body = self._read_body()
         parts, deadline_ms = _parse_assign(
@@ -243,7 +249,8 @@ class _Handler(BaseHTTPRequestHandler):
         timeout = (deadline_ms / 1e3 if deadline_ms is not None
                    else fe.default_deadline_s)
         try:
-            got = fut.result(timeout=timeout)
+            with spans.span("geek.frontend.wait"):
+                got = fut.result(timeout=timeout)
         except FutureTimeoutError:
             raise FrontendError(
                 504, "DeadlineExceeded",
