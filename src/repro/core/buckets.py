@@ -29,6 +29,10 @@ class BucketTables(NamedTuple):
     segments: jax.Array     # (T, n) int32 — dense bucket index within table
     num_buckets: jax.Array  # (T,)  int32 — # non-empty buckets per table
     buckets_per_table: int  # static cap on buckets per table (t or n)
+    # static: every bucket is exactly this many consecutive entries of its
+    # table (so the flat entries are buckets_per_table·T equal runs), or
+    # None when bucket sizes vary or are not known when tracing
+    run_len: int | None = None
 
     @property
     def num_tables(self) -> int:
@@ -57,7 +61,8 @@ def partition_even(h: jax.Array, t: int) -> BucketTables:
 
     h: (n, m) QALSH values. Bucket of the rank-r object is floor(r·t/n), so
     bucket sizes differ by at most one — the paper's granularity-control
-    replacement for the hard-to-tune bucket width w.
+    replacement for the hard-to-tune bucket width w. When t divides n
+    every bucket holds exactly n/t entries, recorded as ``run_len``.
     """
     n, m = h.shape
     order = jnp.argsort(h, axis=0)                      # (n, m) — ids by rank
@@ -65,7 +70,8 @@ def partition_even(h: jax.Array, t: int) -> BucketTables:
     seg = (ranks * t // n).astype(jnp.int32)            # (n,) even partition
     ids = order.T.astype(jnp.int32)                     # (m, n)
     segments = jnp.broadcast_to(seg, (m, n))
-    return BucketTables(ids, segments, jnp.full((m,), t, jnp.int32), t)
+    return BucketTables(ids, segments, jnp.full((m,), t, jnp.int32), t,
+                        n // t if n % t == 0 else None)
 
 
 def partition_by_boundaries(h: jax.Array, boundaries: jax.Array) -> BucketTables:

@@ -69,17 +69,32 @@ def code_items(codes: jax.Array, key: jax.Array) -> jax.Array:
                         hk[0], hk[1])
 
 
+def reduce_runs(x: jax.Array, run_len: int, op) -> jax.Array:
+    """``op`` over each run of ``run_len`` consecutive entries of (P,) ``x``.
+
+    The segment reduction of a layout whose segments are equal contiguous
+    runs (``BucketTables.run_len``), as a dense reduction instead of a
+    scatter into the segments.
+    """
+    with jax.named_scope("geek.silk.runs"):
+        return op(x.reshape(-1, run_len), axis=1)
+
+
 def minhash_over_segments(
     values: jax.Array,           # (P,) int32 member ids (flattened buckets)
     segments: jax.Array,         # (P,) int32 bucket index per member
     num_segments: int,
     keys: jax.Array,             # (K, 2) uint32
     valid: jax.Array | None = None,
+    run_len: int | None = None,
 ) -> jax.Array:
     """(num_segments,) uint32 signature per bucket = K segment-min hashes mixed.
 
     This is MinHash applied to *buckets as sets of data ids* — the core of
-    SILK (paper §3.2); one path on every backend.
+    SILK (paper §3.2). With ``run_len``, segment s must be exactly entries
+    [s·run_len, (s+1)·run_len) and P = num_segments·run_len; each min is
+    then a reduction over the runs (``reduce_runs``), bit-identical to the
+    scatter-min.
     """
     K = keys.shape[0]
     sig = jnp.zeros((num_segments,), jnp.uint32)
@@ -87,7 +102,11 @@ def minhash_over_segments(
         hv = hash_u32(values, keys[k, 0], keys[k, 1])
         if valid is not None:
             hv = jnp.where(valid, hv, UMAX32)
-        mins = jax.ops.segment_min(hv, segments, num_segments=num_segments)
+        if run_len is None:
+            mins = jax.ops.segment_min(hv, segments,
+                                       num_segments=num_segments)
+        else:
+            mins = reduce_runs(hv, run_len, jnp.min)
         sig = mix_u32(sig, mins)
     return sig
 

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.buckets import BucketTables
-from repro.core.lsh import minhash_over_segments
+from repro.core.lsh import minhash_over_segments, reduce_runs
 from repro.utils.hashing import derive_hash_keys, run_starts
 
 
@@ -141,14 +141,24 @@ def silk_round(
     delta: int,               # seeding threshold (paper: delta)
     min_bin_size: int,        # 2 for seeding (skip |Bin|<=1), 1 for dedup
     pair_cap: int,
+    run_len: int | None = None,  # static; ``BucketTables.run_len``
 ) -> SeedPairs:
-    """One SILK table: bucket-minhash -> bins -> majority vote -> cores."""
+    """One SILK table: bucket-minhash -> bins -> majority vote -> cores.
+
+    With ``run_len`` the per-bucket signatures and sizes are reductions
+    over equal runs instead of scatters into ``nbcap`` slots; the result
+    is bit-identical.
+    """
     P = flat_ids.shape[0]
     ones = entry_valid.astype(jnp.int32)
 
     # -- bucket signatures + sizes -----------------------------------------
-    sizes = jax.ops.segment_sum(ones, flat_seg, num_segments=nbcap)
-    sig = minhash_over_segments(flat_ids, flat_seg, nbcap, keys, valid=entry_valid)
+    if run_len is None:
+        sizes = jax.ops.segment_sum(ones, flat_seg, num_segments=nbcap)
+    else:
+        sizes = reduce_runs(ones, run_len, jnp.sum)
+    sig = minhash_over_segments(flat_ids, flat_seg, nbcap, keys,
+                                valid=entry_valid, run_len=run_len)
     bucket_valid = sizes > 0
 
     # -- bins: group buckets by signature (shared with the sharded path) ---
@@ -196,7 +206,8 @@ def select_top_groups(pairs: SeedPairs, group_cap: int, k_max: int) -> Seeds:
 
 
 def silk_rounds(flat_ids, flat_seg, entry_valid, nbcap: int,
-                table_keys: jax.Array, delta: int, pair_cap: int):
+                table_keys: jax.Array, delta: int, pair_cap: int,
+                run_len: int | None = None):
     """The L seeding rounds of Algorithm 4, one per row of ``table_keys``.
 
     The rounds run one after another (``lax.map``), not vmapped: each
@@ -204,13 +215,16 @@ def silk_rounds(flat_ids, flat_seg, entry_valid, nbcap: int,
     held at once do not fit one chip's HBM at n = 10^6. Every fit path
     seeds through here, so they all share that bound.
 
+    ``run_len`` is ``silk_round``'s: set it only when bucket b is
+    exactly entries [b·run_len, (b+1)·run_len).
+
     Returns ``(group, id, valid, overflow)``: the rounds' pairs
     concatenated, round r's group ids offset by ``r * pair_cap`` so they
     stay distinct, and the rounds' total overflow.
     """
     rounds = jax.lax.map(
         lambda tk: silk_round(flat_ids, flat_seg, entry_valid, nbcap, tk,
-                              delta, 2, pair_cap),
+                              delta, 2, pair_cap, run_len),
         table_keys)
     offs = (jnp.arange(table_keys.shape[0], dtype=jnp.int32)
             * pair_cap)[:, None]
@@ -241,7 +255,7 @@ def silk_seeding(
     table_keys = derive_hash_keys(key, (silk_l + 1, silk_k))
     cat_group, cat_ids, cat_valid, round_overflow = silk_rounds(
         flat_ids, flat_seg, entry_valid, nbcap, table_keys[:silk_l], delta,
-        pair_cap)
+        pair_cap, buckets.run_len)
     group_cap = silk_l * pair_cap
 
     # dedup round: cores are buckets now; singleton bins are kept (a unique

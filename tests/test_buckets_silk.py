@@ -1,12 +1,17 @@
 """Bucket construction + SILK invariants (paper §3.1-3.2)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core.buckets import partition_by_signature, partition_even
-from repro.core.silk import select_top_groups, silk_round, silk_seeding
+from repro.core.lsh import minhash_over_segments, reduce_runs
+from repro.core.silk import (select_top_groups, silk_round, silk_rounds,
+                             silk_seeding)
 from repro.utils.hashing import derive_hash_keys
 
 
@@ -273,3 +278,104 @@ def test_hierarchical_merge_matches_silk_round():
     assert set(map(frozenset, members.values())) == {
         frozenset({0, 1, 6, 7}), frozenset({2, 3, 4, 5}),
         frozenset({10, 11})}
+
+
+# -- equal-run buckets: dense run reductions in place of scatters ------------
+
+def _even_entries(key, T, t, run):
+    """Flat entries of T tables of t buckets of ``run`` ids each, laid
+    out as ``BucketTables.flatten`` lays out ``partition_even``."""
+    n = t * run
+    ids = jax.vmap(lambda k: jax.random.permutation(k, n))(
+        jax.random.split(key, T)).astype(jnp.int32)
+    seg = (jnp.arange(T, dtype=jnp.int32)[:, None] * t
+           + jnp.arange(n, dtype=jnp.int32)[None, :] // run)
+    return ids.reshape(-1), seg.reshape(-1)
+
+
+@pytest.mark.parametrize("T,t,run,K,masked", [
+    (1, 4, 5, 1, False),       # one table
+    (1, 3, 7, 3, True),        # one table, entries off
+    (3, 8, 7, 3, False),
+    (4, 16, 1, 2, True),       # runs of one entry
+    (5, 6, 32, 3, True),
+])
+def test_run_reduction_matches_scatter(T, t, run, K, masked):
+    """On equal contiguous runs the run path is bit-identical to the
+    scatter path: bucket signatures, bucket sizes, and whole SILK seeds
+    over a ``partition_even`` table set."""
+    key = jax.random.PRNGKey(T * 1000 + t * 10 + run)
+    k_ids, k_valid, k_hash, k_silk = jax.random.split(key, 4)
+    flat_ids, flat_seg = _even_entries(k_ids, T, t, run)
+    nbcap = T * t
+    valid = (jax.random.bernoulli(k_valid, 0.7, flat_ids.shape) if masked
+             else jnp.ones(flat_ids.shape, bool))
+    keys = derive_hash_keys(k_hash, (K,))
+    np.testing.assert_array_equal(
+        np.array(minhash_over_segments(flat_ids, flat_seg, nbcap, keys,
+                                       valid=valid, run_len=run)),
+        np.array(minhash_over_segments(flat_ids, flat_seg, nbcap, keys,
+                                       valid=valid)))
+    ones = valid.astype(jnp.int32)
+    np.testing.assert_array_equal(
+        np.array(reduce_runs(ones, run, jnp.sum)),
+        np.array(jax.ops.segment_sum(ones, flat_seg, num_segments=nbcap)))
+
+    h = jax.random.normal(k_ids, (t * run, T))
+    buckets = partition_even(h, t)
+    assert buckets.run_len == run
+    kw = dict(silk_k=K, silk_l=3, delta=2, pair_cap=T * t * run, k_max=16)
+    seeds, ovf = silk_seeding(buckets, k_silk, **kw)
+    ref, ref_ovf = silk_seeding(buckets._replace(run_len=None), k_silk, **kw)
+    for got, want in zip(seeds[:4], ref[:4]):
+        np.testing.assert_array_equal(np.array(got), np.array(want))
+    assert int(seeds.k_star) == int(ref.k_star)
+    assert int(ovf) == int(ref_ovf)
+
+
+def _nbcap_scatters(buckets, K):
+    """(u32, s32, scoped) of the lowered ``silk_rounds`` over ``buckets``:
+    the u32 and s32 scatters into the ``nbcap`` bucket slots, and whether
+    the ``geek.silk.runs`` scope is there; with the layout's run length,
+    then with none."""
+    flat_ids, flat_seg = buckets.flatten()
+    nbcap = buckets.total_bucket_cap
+    keys = derive_hash_keys(jax.random.PRNGKey(1), (2, K))
+
+    def counts(run_len):
+        fn = jax.jit(lambda i, s, v, k: silk_rounds(
+            i, s, v, nbcap, k, 2, 64, run_len))
+        text = fn.lower(flat_ids, flat_seg, jnp.ones_like(flat_ids, bool),
+                        keys).as_text(debug_info=True)
+        out = re.findall(r'"stablehlo\.scatter".*?\}\) : \([^)]*\) -> '
+                         r'(tensor<[^>]*>)', text, re.S)
+        return (out.count(f"tensor<{nbcap}xui32>"),
+                out.count(f"tensor<{nbcap}xi32>"), "geek.silk.runs" in text)
+    return counts(buckets.run_len), counts(None)
+
+
+@pytest.mark.parametrize("layout", ["even", "even_ragged", "signature"])
+def test_run_path_replaces_bucket_scatters(layout):
+    """The counter of the run path, read from the lowered program: on a
+    ``partition_even`` layout with t | n the K signature scatter-mins and
+    the sizes scatter into the bucket slots are gone (one scope
+    ``geek.silk.runs`` in their place); the layouts whose buckets are not
+    equal runs keep every scatter."""
+    K, t = 3, 8
+    if layout == "signature":
+        sigs = jax.random.randint(jax.random.PRNGKey(2), (4, 48), 0, 5
+                                  ).astype(jnp.uint32)
+        buckets = partition_by_signature(sigs)
+    else:
+        n = 48 if layout == "even" else 50
+        buckets = partition_even(
+            jax.random.normal(jax.random.PRNGKey(2), (n, 4)), t)
+    (u32, s32, scoped), (u32_ref, s32_ref, scoped_ref) = _nbcap_scatters(
+        buckets, K)
+    assert (u32_ref, scoped_ref) == (K, False)
+    if layout == "even":
+        assert buckets.run_len == 6
+        assert (u32, s32, scoped) == (0, s32_ref - 1, True)
+    else:
+        assert buckets.run_len is None
+        assert (u32, s32, scoped) == (u32_ref, s32_ref, scoped_ref)
